@@ -159,6 +159,8 @@ def _cold_probe_subprocess(mode: str) -> float:
                         os.pardir)
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
         + env.get("PYTHONPATH", "")
+    # the parent estimates too, so on a chip host it holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.perf_estimator",
          "--cold-probe", mode],

@@ -43,11 +43,11 @@ def main():
     cfg = MODEL_100M if args.model_100m else MODEL_DEMO
     print(f"model: {cfg.name} ({cfg.param_count()/1e6:.1f}M params)")
     shape = smoke_shape(seq_len=args.seq, global_batch=args.batch)
-    loss = train_loop(cfg, shape,
-                      TrainPolicy(optimizer="adamw", learning_rate=3e-4),
-                      steps=args.steps, ckpt_dir=args.ckpt_dir,
-                      ckpt_every=50)
-    print(f"final loss: {loss:.4f}")
+    res = train_loop(cfg, shape,
+                     TrainPolicy(optimizer="adamw", learning_rate=3e-4),
+                     steps=args.steps, ckpt_dir=args.ckpt_dir,
+                     ckpt_every=50)
+    print(f"final loss: {res.loss:.4f}")
 
 
 if __name__ == "__main__":

@@ -537,6 +537,13 @@ def _pool_warm(_i: int) -> bool:
     return True
 
 
+def _pin_cpu() -> None:
+    """Pool-worker initializer: workers only estimate, so on a chip host
+    they keep off the accelerator the parent's jobs need."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 class _ColumnarLifecycles(Sequence):
     """Tuple-compatible lifecycles view backed by ``ColumnarBlocks`` —
     crosses process boundaries as arrays, materializes on first use."""
@@ -586,7 +593,8 @@ class SweepService:
             # spawn: workers must not inherit JAX/XLA runtime threads
             self._pool = ProcessPoolExecutor(
                 max_workers=self.processes,
-                mp_context=mp.get_context("spawn"))
+                mp_context=mp.get_context("spawn"),
+                initializer=_pin_cpu)
         return self._pool
 
     def warm_up(self) -> None:

@@ -90,13 +90,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     jax.jit,
     static_argnames=("causal", "window", "block_q", "block_k",
                      "interpret", "kv_len"))
-def flash_attention_bhsd(q, k, v, *, causal: bool = True,
+def flash_attention_bhsd(q, k, v, *, interpret: bool, causal: bool = True,
                          window: int | None = None, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True,
-                         kv_len: int | None = None):
+                         block_k: int = 128, kv_len: int | None = None):
     """q: [B, H, Sq, d]; k/v: [B, Hkv, Sk, d] -> [B, H, Sq, d].
 
     Sq/Sk must be padded to block multiples (ops.py handles padding).
+    ``interpret`` has no default: on a TPU a caller that forgot it would
+    silently run the Python interpreter instead of the Mosaic kernel.
     """
     B, H, Sq, d = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]

@@ -280,6 +280,8 @@ def main():
                     help="on rejection, search serving counter-offers")
     args = ap.parse_args()
 
+    from .device import enable_compile_cache
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     from ..service import AdmissionService
     svc = AdmissionService(workers=1, store_dir=args.store_dir)

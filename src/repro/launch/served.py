@@ -550,6 +550,10 @@ def main():
                          "(crash-safe JSONL; implies --metrics)")
     args = ap.parse_args()
 
+    # the daemon only estimates: on a chip host it must leave the chip
+    # to the jobs it admits, so it never initializes an accelerator
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     from ..service import AdmissionService
     obs = None
     if args.metrics or args.audit_dir:

@@ -10,9 +10,12 @@ Flow:
   4. step loop with periodic checkpoints, straggler monitoring, and an
      emergency checkpoint on any exception.
 
-On this CPU box, use smoke-scale flags:
+On the CPU, use smoke-scale flags (the gate then holds the job to a
+v5e's nominal 16 GiB unless ``--hbm-gib`` says otherwise):
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-32b --smoke \
       --steps 20 --ckpt-dir /tmp/ckpt
+On a TPU the capacity is the device's own ``bytes_limit``;
+``chip_smoke.py`` at the repo root drives this loop at full width.
 """
 from __future__ import annotations
 
@@ -29,14 +32,14 @@ from ..core.estimator import XMemEstimator
 from ..models import model as M
 from ..train import (CheckpointManager, StragglerMonitor, SyntheticDataset,
                      TrainPolicy, make_estimator_hooks, make_train_step)
-
-HBM_BYTES = 16 * 2**30     # v5e
+from . import device as D
 
 
 def admission_check(cfg, policy: TrainPolicy, shape: ShapeSpec,
-                    hbm_bytes: int = HBM_BYTES, shard_factor_fn=None,
+                    hbm_bytes: int = D.V5E_HBM_BYTES, shard_factor_fn=None,
                     verbose: bool = True, est: XMemEstimator | None = None,
-                    service=None, return_decision: bool = False):
+                    service=None, return_decision: bool = False,
+                    collective_specs=()):
     """xMem gate: estimate peak device memory a priori (CPU-only).
 
     Decisions route through the admission service
@@ -58,7 +61,8 @@ def admission_check(cfg, policy: TrainPolicy, shape: ShapeSpec,
         job_id=f"{cfg.name}/{shape.name}/mb{policy.microbatches}",
         fwd_bwd_fn=fwd_bwd, params=params, batch=batch,
         update_fn=update, opt_init_fn=opt_init,
-        shard_factor_fn=shard_factor_fn, capacity=hbm_bytes))
+        shard_factor_fn=shard_factor_fn, collective_specs=collective_specs,
+        capacity=hbm_bytes))
     rep = decision.report
     ok = decision.admit
     if verbose:
@@ -114,16 +118,41 @@ def replan_if_needed(cfg, policy: TrainPolicy, shape, hbm_bytes,
     return policy, rep
 
 
+@dataclasses.dataclass
+class TrainResult:
+    """What one :func:`train_loop` run did."""
+
+    policy: TrainPolicy             # after any replan by the gate
+    report: object | None           # the gate's EstimateReport (None: skipped)
+    bytes_after_gate: int | None    # device bytes in use after the gate,
+                                    # before init (None: not reported)
+    start_step: int = 0
+    losses: list = dataclasses.field(default_factory=list)  # per step run
+    # blocked wall seconds per step; the first one includes compilation
+    step_s: list = dataclasses.field(default_factory=list)
+    ckpt_s: float = float("nan")    # the final checkpoint save
+
+    @property
+    def loss(self) -> float:
+        return self.losses[-1] if self.losses else float("nan")
+
+
 def train_loop(cfg, shape, policy: TrainPolicy, *, steps: int,
                ckpt_dir: str, ckpt_every: int = 20,
-               hbm_bytes: int = HBM_BYTES, skip_gate: bool = False) -> float:
+               hbm_bytes: int | None = None,
+               skip_gate: bool = False) -> TrainResult:
     """The reusable training loop (admission gate -> resume -> steps ->
-    checkpoints -> emergency save). Returns the final loss."""
-    import time as _time
+    checkpoints -> emergency save). ``hbm_bytes`` defaults to the
+    device's own capacity (:func:`repro.launch.device.hbm_bytes`)."""
+    if hbm_bytes is None:
+        hbm_bytes = D.hbm_bytes()
+    rep = None
     if not skip_gate:
         policy, rep = replan_if_needed(cfg, policy, shape, hbm_bytes)
         if rep.peak_bytes > hbm_bytes:
             raise MemoryError("xmem gate: job will not fit — rejected")
+    res = TrainResult(policy=policy, report=rep,
+                      bytes_after_gate=D.bytes_in_use())
     train_step, opt = make_train_step(cfg, policy)
     step_fn = jax.jit(train_step, donate_argnums=(0, 1))
     ckpt = CheckpointManager(ckpt_dir)
@@ -131,34 +160,44 @@ def train_loop(cfg, shape, policy: TrainPolicy, *, steps: int,
     monitor = StragglerMonitor(n_workers=1)
     params = M.init_params(cfg, jax.random.key(0))
     opt_state = opt.init(params)
-    start_step = 0
     restored = ckpt.restore_latest({"params": params,
                                     "opt_state": opt_state})
     if restored is not None:
-        start_step, state = restored
+        res.start_step, state = restored
         params, opt_state = state["params"], state["opt_state"]
-        print(f"[ckpt] resumed from step {start_step}")
-    loss = float("nan")
-    step = start_step
+        print(f"[ckpt] resumed from step {res.start_step}")
+    step = res.start_step
     try:
-        for step in range(start_step, steps):
-            t0 = _time.perf_counter()
+        for step in range(res.start_step, steps):
+            t0 = time.perf_counter()
             batch = jax.tree_util.tree_map(jnp.asarray, ds.batch(step))
             loss, params, opt_state = step_fn(params, opt_state, batch)
-            dt = _time.perf_counter() - t0
+            # time the device's work, not its enqueue
+            jax.block_until_ready((loss, params, opt_state))
+            dt = time.perf_counter() - t0
             monitor.record(0, dt)
+            res.step_s.append(dt)
+            res.losses.append(float(loss))
             if step % 10 == 0 or step == steps - 1:
-                print(f"step {step:5d} loss {float(loss):.4f} "
+                print(f"step {step:5d} loss {res.loss:.4f} "
                       f"({dt*1000:.0f} ms)")
             if (step + 1) % ckpt_every == 0:
                 ckpt.save(step + 1, {"params": params,
                                      "opt_state": opt_state})
-    except BaseException:
-        ckpt.emergency(step, {"params": params, "opt_state": opt_state})
-        print(f"[ckpt] emergency checkpoint at step {step}")
+    except BaseException as exc:
+        # after a failed step the donated buffers may be gone: a failing
+        # emergency save is noted on the original error, never raised
+        try:
+            ckpt.emergency(step, {"params": params, "opt_state": opt_state})
+            print(f"[ckpt] emergency checkpoint at step {step}")
+        except Exception as save_exc:  # noqa: BLE001 — exc is re-raised
+            exc.add_note(f"emergency checkpoint at step {step} failed: "
+                         f"{type(save_exc).__name__}: {save_exc}")
         raise
+    t0 = time.perf_counter()
     ckpt.save(steps, {"params": params, "opt_state": opt_state})
-    return float(loss)
+    res.ckpt_s = time.perf_counter() - t0
+    return res
 
 
 def main():
@@ -173,25 +212,28 @@ def main():
     ap.add_argument("--optimizer", default="adamw")
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--hbm-gib", type=float, default=16.0)
+    ap.add_argument("--hbm-gib", type=float, default=None,
+                    help="gate capacity (default: the device's own "
+                         "bytes_limit; 16 on the CPU)")
     ap.add_argument("--skip-gate", action="store_true")
     args = ap.parse_args()
 
+    D.enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     shape = smoke_shape(args.seq, args.batch) if args.smoke else TRAIN_4K
     policy = TrainPolicy(optimizer=args.optimizer,
                          learning_rate=args.lr,
                          microbatches=args.microbatches)
     try:
-        loss = train_loop(cfg, shape, policy, steps=args.steps,
-                          ckpt_dir=args.ckpt_dir,
-                          ckpt_every=args.ckpt_every,
-                          hbm_bytes=int(args.hbm_gib * 2**30),
-                          skip_gate=args.skip_gate)
+        res = train_loop(
+            cfg, shape, policy, steps=args.steps, ckpt_dir=args.ckpt_dir,
+            ckpt_every=args.ckpt_every, skip_gate=args.skip_gate,
+            hbm_bytes=(None if args.hbm_gib is None
+                       else int(args.hbm_gib * 2**30)))
     except MemoryError as e:
         print(f"[xmem] {e}")
         return 2
-    print("[done] final loss", loss)
+    print("[done] final loss", res.loss)
     return 0
 
 

@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -144,8 +143,10 @@ def init_params(cfg: ModelConfig, key) -> dict:
 
 
 def abstract_params(cfg: ModelConfig) -> dict:
-    """Parameter ShapeDtypeStructs without any allocation (dry-run)."""
-    return jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
+    """Parameter ShapeDtypeStructs without any allocation (dry-run). The
+    key is made inside the trace: a concrete one would run two small
+    programs on the default device, which the host-side gate must not."""
+    return jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
 
 
 # ---------------------------------------------------------------------------

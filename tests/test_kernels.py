@@ -35,8 +35,8 @@ TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 ])
 def test_flash_vs_ref_shapes(B, H, Hkv, S, d, dtype):
     q, k, v = _make(B, H, Hkv, S, S, d, dtype)
-    out = flash_attention_bhsd(q, k, v, causal=True, block_q=64,
-                               block_k=64)
+    out = flash_attention_bhsd(q, k, v, interpret=True, causal=True,
+                               block_q=64, block_k=64)
     ref = attention_ref(q, k, v, causal=True)
     err = jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)).max()
     assert float(err) < TOL[dtype], f"err {err}"
@@ -45,16 +45,16 @@ def test_flash_vs_ref_shapes(B, H, Hkv, S, d, dtype):
 @pytest.mark.parametrize("window", [32, 64, 100])
 def test_flash_sliding_window(window):
     q, k, v = _make(1, 4, 2, 256, 256, 64, jnp.float32)
-    out = flash_attention_bhsd(q, k, v, causal=True, window=window,
-                               block_q=64, block_k=64)
+    out = flash_attention_bhsd(q, k, v, interpret=True, causal=True,
+                               window=window, block_q=64, block_k=64)
     ref = attention_ref(q, k, v, causal=True, window=window)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
 def test_flash_non_causal():
     q, k, v = _make(1, 2, 2, 128, 128, 64, jnp.float32)
-    out = flash_attention_bhsd(q, k, v, causal=False, block_q=64,
-                               block_k=64)
+    out = flash_attention_bhsd(q, k, v, interpret=True, causal=False,
+                               block_q=64, block_k=64)
     ref = attention_ref(q, k, v, causal=False)
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
@@ -83,8 +83,8 @@ def test_flash_property_sweep(S, d, H, G, causal):
     """Property: kernel == oracle across random shape combinations."""
     Hkv = max(H // G, 1)
     q, k, v = _make(1, H, Hkv, S, S, d, jnp.float32, seed=S + d)
-    out = flash_attention_bhsd(q, k, v, causal=causal, block_q=64,
-                               block_k=64)
+    out = flash_attention_bhsd(q, k, v, interpret=True, causal=causal,
+                               block_q=64, block_k=64)
     ref = attention_ref(q, k, v, causal=causal)
     assert float(jnp.abs(out - ref).max()) < 3e-5
 

@@ -205,3 +205,46 @@ class TestData:
                 assert "patch_embeds" in b
             else:
                 assert b["codes"].shape[-1] == cfg.num_codebooks
+
+
+# ---------------------------------------------------------------------------
+class TestTrainLoop:
+    """``launch/train.py``: the gate -> init -> steps -> checkpoint path."""
+
+    def test_gate_moves_nothing_to_the_device(self):
+        from repro.launch.train import admission_check
+        # the gate only traces and replays: any host-to-device transfer
+        # (a concrete PRNG key, a constant) would raise here
+        with jax.transfer_guard("disallow"):
+            ok, rep = admission_check(get_smoke("starcoder2-3b"),
+                                      TrainPolicy(), smoke_shape(32, 2),
+                                      verbose=False)
+        assert ok and rep.peak_bytes > rep.persistent_bytes > 0
+
+    def test_result_records_every_step(self, tmp_path):
+        from repro.launch.train import train_loop
+        res = train_loop(get_smoke("starcoder2-3b"), smoke_shape(32, 2),
+                         TrainPolicy(), steps=3, ckpt_dir=str(tmp_path))
+        assert res.start_step == 0 and res.report is not None
+        assert len(res.losses) == len(res.step_s) == 3
+        assert all(np.isfinite(res.losses)) and res.loss == res.losses[-1]
+        assert min(res.step_s) > 0 and res.ckpt_s > 0
+        assert CheckpointManager(str(tmp_path)).latest_step() == 3
+
+    def test_failed_emergency_save_keeps_the_original_error(
+            self, tmp_path, monkeypatch):
+        from repro.launch.train import train_loop
+
+        def bad_batch(self, step):
+            raise RuntimeError("step failed")
+
+        def bad_save(self, step, state):
+            raise IOError("disk gone")
+        monkeypatch.setattr(SyntheticDataset, "batch", bad_batch)
+        monkeypatch.setattr(CheckpointManager, "emergency", bad_save)
+        with pytest.raises(RuntimeError, match="step failed") as ei:
+            train_loop(get_smoke("starcoder2-3b"), smoke_shape(32, 2),
+                       TrainPolicy(), steps=2, ckpt_dir=str(tmp_path),
+                       skip_gate=True)
+        assert any("emergency checkpoint at step 0 failed: OSError: "
+                   "disk gone" in n for n in ei.value.__notes__)
