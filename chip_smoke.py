@@ -95,6 +95,7 @@ def one_chip(cfg, shape, steps: int) -> int:
     capacity = D.hbm_bytes(dev)
     print(f"capacity (bytes_limit): {capacity} ({capacity / GiB:.3f} GiB)")
     before_gate = D.bytes_in_use(dev)
+    compiles = D.CompileCounter().install()
     # a fresh directory: a stale checkpoint would make restore skip steps
     with tempfile.TemporaryDirectory(prefix="chip_smoke_ckpt_") as ckpt:
         try:
@@ -124,6 +125,9 @@ def one_chip(cfg, shape, steps: int) -> int:
     print(f"loss first {res.losses[0]:.6f} last {res.losses[-1]:.6f} "
           f"(all: {[round(x, 4) for x in res.losses]})")
     print(f"final checkpoint save: {res.ckpt_s:.2f} s")
+    print(f"programs compiled or loaded from the cache in the run: "
+          f"{compiles.count} ({compiles.seconds:.2f} s)")
+    compiles.remove()
     print(f"peak_bytes_in_use {peak} B ({peak / GiB:.3f} GiB) = "
           f"{peak / rep.peak_bytes:.4f} x the estimate")
     print(f"memory_stats: {json.dumps(dev.memory_stats(), sort_keys=True)}")
@@ -192,6 +196,8 @@ def four_chips(cfg, shape, steps: int, n: int = 4) -> int:
 
     ds = SyntheticDataset(cfg, shape)
     losses, step_s = [], []
+    compiles = D.CompileCounter().install()
+    after_first = None
     try:
         with mesh, logical_axis_rules(mesh, DEFAULT_RULES):
             params = jax.jit(partial(M.init_params, cfg),
@@ -207,6 +213,8 @@ def four_chips(cfg, shape, steps: int, n: int = 4) -> int:
                 jax.block_until_ready((loss, params, opt_state))
                 step_s.append(time.perf_counter() - t0)
                 losses.append(float(loss))
+                if i == 0:
+                    after_first = compiles.count
     except Exception as e:
         _oom_note(e, capacity)
         raise
@@ -233,6 +241,9 @@ def four_chips(cfg, shape, steps: int, n: int = 4) -> int:
               f"{(pk + temps) / rep.peak_bytes:.4f} x")
     print(f"first step (compile + run): {step_s[0]:.3f} s; median blocked "
           f"step after it: {statistics.median(step_s[1:]) * 1e3:.1f} ms")
+    print(f"programs compiled in steps 1..{steps - 1}: "
+          f"{compiles.count - after_first} (expected 0)")
+    compiles.remove()
     print(f"losses: {[round(x, 4) for x in losses]}")
 
     # what it is compared with: an unsharded forward of the same params

@@ -50,7 +50,6 @@ import weakref
 from collections import OrderedDict
 from typing import Any, Sequence
 
-from ..obs import spans as obs_spans
 from .events import BlockKind, BlockLifecycle, Trace
 
 
@@ -333,15 +332,6 @@ class TraceCache:
         setattr(self, field, getattr(self, field) + 1)
         t = self._tlocal()
         setattr(t, field, getattr(t, field) + 1)
-        # ISSUE 10: annotate the active request trace. Memory hits are
-        # deliberately NOT annotated — they are the common case on the
-        # warm decide path (three per decision, visible in the cache
-        # counters and implied by the replay span's provenance), and
-        # skipping them keeps instrumentation inside the <3% overhead
-        # gate; misses and store promotions are the events worth a
-        # trace line
-        if field != "hits":
-            obs_spans.event(f"trace_cache.{field}")
 
     def get(self, fn, key: tuple | None) -> TracedPhase | None:
         if key is None:

@@ -37,7 +37,6 @@ from typing import Sequence
 
 import numpy as np
 
-from ..obs import spans as obs_spans
 from .allocator import (AllocatorPolicy, CachingAllocatorSim, CUDA_CACHING,
                         DeviceAllocatorSim, SimOOMError, default_space_specs,
                         round_size_array, round_up, round_up_array)
@@ -328,38 +327,35 @@ class MemorySimulator:
     def replay(self, blocks, steady_state: bool = True) -> SimResult:
         """Replay a flat lifecycle list, a ``PeriodicBlocks`` composition
         or a prebuilt ``ColumnarProgram``."""
-        # ISSUE 10: replay span — one ContextVar.get when observability
-        # is off; the replay itself is untouched either way
-        with obs_spans.span("simulator.replay", engine=self.engine):
-            if self.engine == "columnar" \
-                    or isinstance(blocks, ColumnarProgram):
-                prog = self.as_program(blocks)
-                if prog is not None:
-                    return self.replay_program(prog)
-            if isinstance(blocks, PeriodicBlocks):
-                return self._replay_periodic(blocks, steady_state)
-            if isinstance(blocks, ComposedBlocks):
-                blocks = blocks.materialize()
-            events = lifecycles_to_events(blocks)
-            device = DeviceAllocatorSim(self.capacity,
-                                        self.policy.device_page)
-            sim = CachingAllocatorSim(self.policy, device)
-            handles: dict[int, int] = {}
-            oom, oom_at = False, None
-            for i, e in enumerate(events):
-                try:
-                    if e.kind == "alloc":
-                        if e.size <= 0:
-                            continue
-                        handles[e.block_id] = sim.malloc(e.size, t=e.t)
-                    else:
-                        h = handles.pop(e.block_id, None)
-                        if h is not None:
-                            sim.free(h, t=e.t)
-                except SimOOMError:
-                    oom, oom_at = True, i
-                    break
-            return self._result(sim, oom, oom_at)
+        if self.engine == "columnar" \
+                or isinstance(blocks, ColumnarProgram):
+            prog = self.as_program(blocks)
+            if prog is not None:
+                return self.replay_program(prog)
+        if isinstance(blocks, PeriodicBlocks):
+            return self._replay_periodic(blocks, steady_state)
+        if isinstance(blocks, ComposedBlocks):
+            blocks = blocks.materialize()
+        events = lifecycles_to_events(blocks)
+        device = DeviceAllocatorSim(self.capacity,
+                                    self.policy.device_page)
+        sim = CachingAllocatorSim(self.policy, device)
+        handles: dict[int, int] = {}
+        oom, oom_at = False, None
+        for i, e in enumerate(events):
+            try:
+                if e.kind == "alloc":
+                    if e.size <= 0:
+                        continue
+                    handles[e.block_id] = sim.malloc(e.size, t=e.t)
+                else:
+                    h = handles.pop(e.block_id, None)
+                    if h is not None:
+                        sim.free(h, t=e.t)
+            except SimOOMError:
+                oom, oom_at = True, i
+                break
+        return self._result(sim, oom, oom_at)
 
     @staticmethod
     def _result(sim: CachingAllocatorSim, oom: bool, oom_at,

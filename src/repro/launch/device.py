@@ -1,4 +1,5 @@
-"""What the launchers read from the device they run on.
+"""What the launchers read from the device they run on, and how many
+programs they compile.
 
 Entry points (``chip_smoke.py``, the ``main()`` of ``launch/train.py`` and
 ``launch/serve.py``) call :func:`enable_compile_cache` before their first
@@ -29,6 +30,33 @@ def enable_compile_cache() -> str:
     path = os.path.join(REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
+
+
+class CompileCounter:
+    """Counts the executables JAX builds for this process, and their
+    seconds: a ``jax.monitoring`` duration listener on the backend-compile
+    event, which JAX records around every compile, including one answered
+    from the persistent compilation cache. Entry points that time steps
+    (``chip_smoke.py``) install one to show that nothing compiles inside
+    a timed loop; library code never does."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+
+    def __call__(self, event: str, duration: float, **_) -> None:
+        if event == self.EVENT:
+            self.count += 1
+            self.seconds += duration
+
+    def install(self) -> "CompileCounter":
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def remove(self) -> None:
+        jax.monitoring.unregister_event_duration_listener(self)
 
 
 def hbm_bytes(device=None) -> int:

@@ -158,31 +158,38 @@ def _global_flags(cfg: ModelConfig) -> jnp.ndarray | None:
     return jnp.array([(i % ge) == ge - 1 for i in range(cfg.n_layers)])
 
 
+# Named scopes (``jax.named_scope``) label the step's layers in the
+# compiled program's ``op_name`` metadata, so a device trace's time can be
+# put down to them; they add no operation. A scope name must not contain
+# ``transpose`` or ``backward``: the estimator reads those in the name
+# stack as the backward pass (``core/analyzer.py:_BWD_MARKERS``).
 def _decoder_layer(x, lp, cfg: ModelConfig, *, is_global=None,
                    positions=None):
     window = cfg.attention.sliding_window
-    h, _ = attention_block(
-        rms_norm(x, lp["ln1"]), lp["attn"], cfg.attention, cfg.n_heads,
-        cfg.n_kv_heads, cfg.hd, positions=positions, is_global=is_global,
-        window=window)
-    x = x + h
-    xn = rms_norm(x, lp["ln2"])
-    if "moe" in lp:
-        x = x + moe_mod.moe_ffn(xn, lp["moe"], cfg.moe)
-    elif "mlp" in lp:
-        x = x + swiglu(xn, **lp["mlp"])
-    return x
+    with jax.named_scope("attn"):
+        h, _ = attention_block(
+            rms_norm(x, lp["ln1"]), lp["attn"], cfg.attention, cfg.n_heads,
+            cfg.n_kv_heads, cfg.hd, positions=positions,
+            is_global=is_global, window=window)
+        x = x + h
+    return _ffn(x, lp, cfg)
+
+
+def _ffn(x, lp, cfg: ModelConfig):
+    """The layer's feed-forward half: pre-norm, MoE or gated MLP, and the
+    residual add."""
+    with jax.named_scope("moe" if "moe" in lp else "mlp"):
+        xn = rms_norm(x, lp["ln2"])
+        if "moe" in lp:
+            x = x + moe_mod.moe_ffn(xn, lp["moe"], cfg.moe)
+        elif "mlp" in lp:
+            x = x + swiglu(xn, **lp["mlp"])
+        return x
 
 
 def _mamba_layer(x, lp, cfg: ModelConfig):
     h, _ = mam.mamba_block(rms_norm(x, lp["ln1"]), lp["mamba"], cfg.mamba)
-    x = x + h
-    xn = rms_norm(x, lp["ln2"])
-    if "moe" in lp:
-        x = x + moe_mod.moe_ffn(xn, lp["moe"], cfg.moe)
-    elif "mlp" in lp:
-        x = x + swiglu(xn, **lp["mlp"])
-    return x
+    return _ffn(x + h, lp, cfg)
 
 
 def _remat(fn, cfg: ModelConfig):
@@ -243,6 +250,11 @@ def backbone(params, x, cfg: ModelConfig, positions=None):
 def embed_inputs(params, batch: dict, cfg: ModelConfig):
     """Family-specific input embedding. Modality frontends are stubs:
     VLM patch embeddings / audio EnCodec tokens arrive precomputed."""
+    with jax.named_scope("embed"):
+        return _embed(params, batch, cfg)
+
+
+def _embed(params, batch: dict, cfg: ModelConfig):
     if cfg.family == "vlm":
         text = jnp.take(params["embed"], batch["tokens"], axis=0)
         x = jnp.concatenate(
@@ -274,12 +286,11 @@ def loss_fn(params, batch: dict, cfg: ModelConfig):
     x = embed_inputs(params, batch, cfg)
     positions = jnp.arange(x.shape[1])
     h = backbone(params, x, cfg, positions=positions)
-    if cfg.family == "vlm":
-        h = h[:, batch["patch_embeds"].shape[1]:]  # loss on text positions
-    logits = logits_fn(params, h, cfg)
-    if cfg.num_codebooks:
+    with jax.named_scope("head_loss"):
+        if cfg.family == "vlm":
+            h = h[:, batch["patch_embeds"].shape[1]:]  # loss on text
+        logits = logits_fn(params, h, cfg)
         return cross_entropy_loss(logits, batch["labels"])
-    return cross_entropy_loss(logits, batch["labels"])
 
 
 # ---------------------------------------------------------------------------

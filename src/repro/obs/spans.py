@@ -3,9 +3,10 @@
 A :class:`Tracer` collects context-manager spans with monotonic
 timings, parent links, and a per-request **correlation ID** minted in
 ``AdmissionService.decide`` and carried — via a ``contextvars``
-context — through ``TraceCache`` lookups, the columnar replay, the
-degradation-ladder rungs, ``RemediationPlanner`` searches and
-``FleetScheduler`` placements/evictions. Finished spans export as
+context — through the exact rung, the estimator's tracing and replay
+(``estimator.trace`` / ``estimator.replay``), the trace store,
+``RemediationPlanner`` searches and ``FleetScheduler``
+placements/evictions. Finished spans export as
 Chrome-trace / Perfetto JSON (:meth:`Span.to_chrome_trace` /
 :meth:`Tracer.to_chrome_trace`).
 

@@ -543,7 +543,6 @@ class AdmissionService:
                     # still answer from a lower rung
                     delay = max(min(delay, remaining * 0.5), 0.0)
                 self._m_retries.inc()
-                obs_spans.event("rung.retry", attempt=attempt)
                 time.sleep(delay)
             except RungTimeout as e:
                 errors.append(f"timeout: {e}")
@@ -585,7 +584,6 @@ class AdmissionService:
                            ) -> AdmissionDecision:
         margin = self.degrade.margin_for(rung)
         peak = int(math.ceil(raw_peak * margin))
-        obs_spans.event(f"rung.{rung}", derived=how, margin=margin)
         prov = {"source": "degraded", "rung": rung, "margin": margin,
                 "derived": how, "rung_errors": list(errors),
                 "trace_cache": {}}

@@ -31,7 +31,6 @@ import dataclasses
 import threading
 from typing import Any
 
-from ..obs import spans as obs_spans
 
 #: Rung names, in degradation order.
 RUNG_EXACT = "exact"
@@ -173,12 +172,10 @@ class DecisionLog:
         with self._lock:
             pts = self._data.get(family)
             if not pts:
-                obs_spans.event("decision_log.miss")
                 return None
             points = sorted(pts.values(), key=lambda p: p.scalar)
         exact = next((p for p in points if p.scalar == scalar), None)
         if exact is not None:
-            obs_spans.event("decision_log.hit", derived="cached")
             return exact.peak, "cached"
         if len(points) >= 2:
             # the two nearest points bracket (or best-effort flank) the
@@ -193,10 +190,8 @@ class DecisionLog:
             slope = (hi.peak - lo.peak) / (hi.scalar - lo.scalar)
             peak = lo.peak + slope * (scalar - lo.scalar)
             floor = max(lo.persistent, hi.persistent)
-            obs_spans.event("decision_log.hit", derived="interpolated")
             return max(int(peak), floor), "interpolated"
         p = points[0]
-        obs_spans.event("decision_log.hit", derived="scaled")
         if p.scalar <= 0:
             return p.peak, "scaled"
         # one point: persistent stays, transients scale with the batch

@@ -223,7 +223,6 @@ class TraceStore:
             return None
         with self._lock:
             self.quarantined += 1
-        obs_spans.event("store.quarantine", reason=reason)
         return dest
 
     def _recover(self) -> dict:

@@ -88,7 +88,10 @@ def make_train_step(cfg: ModelConfig, policy: TrainPolicy
 
     def train_step(params, opt_state, batch):
         loss, grads = fwd_bwd(params, batch)
-        new_params, new_state = update_fn(params, grads, opt_state)
+        # a named scope labels the update's operations in the compiled
+        # program's metadata (see models/model.py); it adds no operation
+        with jax.named_scope("optimizer"):
+            new_params, new_state = update_fn(params, grads, opt_state)
         return loss, new_params, new_state
 
     return train_step, opt
