@@ -20,12 +20,12 @@ from .events import (TRACE_SCHEMA_VERSION, BlockKind, BlockLifecycle,
                      lifecycles_to_events, liveness_curve, peak_live_bytes,
                      periodic_breakdown_peaks, periodic_peak_live,
                      periodic_phase_peaks, reduced_for_breakdown)
-from .orchestrator import (CollectiveSpec, FUSIBLE_OPS, MemoryOrchestrator,
+from .orchestrator import (CollectiveSpec, MemoryOrchestrator,
                            OrchestratorPolicy)
 from .simulator import (ColumnarProgram, MemorySimulator, SimResult,
                         program_from_lifecycles, program_from_periodic)
 from .sweep import SweepPoint, SweepService, estimate_many
-from .tracer import (JaxprMemoryTracer, aval_bytes, trace_fn,
+from .tracer import (FUSIBLE_OPS, JaxprMemoryTracer, aval_bytes, trace_fn,
                      trace_fn_with_shape)
 
 __all__ = [

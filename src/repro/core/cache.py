@@ -12,7 +12,11 @@ stream, the reconstructed lifecycles, the input/output block summaries
 and the abstract output pytree — keyed on
 
     (function identity, input avals + treedefs, arg kinds,
-     scan_unroll_cap, phase, call-site tag)
+     scan_unroll_cap, phase, call-site tag, fusion_folding)
+
+A trace made with fusion folding (fusion interiors marked, their operands
+held) and one made without are different products of one function, so
+the flag is part of the key and neither is served for the other.
 
 Function identity is **content-addressed** whenever possible: a
 structural digest over the function's code object (bytecode, consts,
@@ -262,8 +266,10 @@ def fn_identity(fn) -> tuple | None:
 
 def trace_key(fn, tag: str, flat_leaves: Sequence, treedefs: tuple,
               kinds: Sequence[BlockKind], scan_unroll_cap: int,
-              phase) -> tuple | None:
-    """Build a cache key, or None when ``fn`` has no safe identity."""
+              phase, *, fusion_folding: bool) -> tuple | None:
+    """Build a cache key, or None when ``fn`` has no safe identity.
+    ``fusion_folding`` is the tracer's flag the trace is made with; it
+    has no default, so no caller can key a folded trace as unfolded."""
     ident = fn_identity(fn)
     if ident is None:
         return None
@@ -274,6 +280,7 @@ def trace_key(fn, tag: str, flat_leaves: Sequence, treedefs: tuple,
         tuple(k.value for k in kinds),
         scan_unroll_cap,
         getattr(phase, "value", phase),
+        bool(fusion_folding),
     )
 
 
@@ -285,9 +292,9 @@ def stable_key_digest(key: tuple) -> str:
     """Process-independent string digest for a content-addressed key —
     the persistent store's file name. Treedefs (the only non-reprable
     component) serialize via their deterministic ``str`` form."""
-    ident, tag, avals, treedefs, kinds, cap, phase = key
+    ident, tag, avals, treedefs, kinds, cap, phase, fold = key
     parts = (ident, tag, avals, tuple(str(t) for t in treedefs), kinds,
-             cap, phase)
+             cap, phase, fold)
     return hashlib.sha256(repr(parts).encode()).hexdigest()
 
 

@@ -48,8 +48,8 @@ from .allocator import AllocatorPolicy, CUDA_CACHING
 from .analyzer import classify_blocks, phase_peaks
 from .cache import (BlockInfo, GLOBAL_TRACE_CACHE, TraceCache, TracedPhase,
                     trace_key)
-from .events import (BlockKind, BlockLifecycle, PeriodicBlocks, Phase,
-                     peak_live_bytes, periodic_breakdown_peaks,
+from .events import (KIND_CODE, BlockKind, BlockLifecycle, PeriodicBlocks,
+                     Phase, peak_live_bytes, periodic_breakdown_peaks,
                      periodic_breakdown_peaks_fast, reduced_for_breakdown)
 from .orchestrator import (CollectiveSpec, MemoryOrchestrator, OffloadPlan,
                            OrchestratorPolicy)
@@ -218,6 +218,22 @@ def update_grad_coupling(update_fn: Callable, params, grads,
     return _coupling_from_jaxpr(closed.jaxpr, n_params, n_grads)
 
 
+def fused_counts(*phases: TracedPhase | None) -> dict:
+    """``fused_blocks``: the values the tracer kept inside XLA fusions
+    (never allocated in HBM) over the given phase traces, and
+    ``fused_bytes``, their bytes."""
+    code = KIND_CODE[BlockKind.FUSED]
+    n = nbytes = 0
+    for ph in phases:
+        if ph is None:
+            continue
+        cols = ph.trace.columnar()
+        m = (cols.kind == 1) & (cols.block_kind == code)
+        n += int(m.sum())
+        nbytes += int(cols.size[m].sum())
+    return {"fused_blocks": n, "fused_bytes": nbytes}
+
+
 def flatten_kinds(args_with_kinds: Sequence[tuple]) -> tuple[list, list[BlockKind], list[str]]:
     """Flatten (pytree, kind, name) triples into tracer-aligned lists."""
     flat_args, kinds, scopes = [], [], []
@@ -356,10 +372,12 @@ class XMemEstimator:
         treedefs = tuple(jax.tree_util.tree_structure(t)
                          for t, _, _ in args_with_kinds)
         cache = self.trace_cache
+        fold = self.orchestrator.policy.fusion_folding
         key = None
         if cache is not None:
             key = trace_key(fn, tag, flat, treedefs, kinds,
-                            self.scan_unroll_cap, phase)
+                            self.scan_unroll_cap, phase,
+                            fusion_folding=fold)
             hit = cache.get(fn, key)
             if hit is not None:
                 return hit
@@ -380,7 +398,8 @@ class XMemEstimator:
                             tag=tag):
             trace, tr, out_shape, closed = trace_fn_with_shape(
                 flat_fn, *flat, arg_kinds=kinds, arg_scopes=scopes,
-                scan_unroll_cap=self.scan_unroll_cap, phase=phase)
+                scan_unroll_cap=self.scan_unroll_cap, phase=phase,
+                fusion_folding=fold)
         out_kinds = out_kind_fn(out_shape) if out_kind_fn is not None else None
         kind_by_bid = {}
         if out_kinds is not None:
@@ -832,6 +851,7 @@ class XMemEstimator:
             "phase_peaks": phase_pk,
             "num_blocks": pb.num_blocks,
             "liveness_peak": liveness_peak,
+            **fused_counts(fwd, upd),
         }
         if offload_stats is not None:
             breakdown["space_peaks"] = sim.stats.get("space_peaks", {})
@@ -981,6 +1001,7 @@ class XMemEstimator:
                 "phase_peaks": phase_peaks(blocks),
                 "num_blocks": len(blocks),
                 "liveness_peak": peak_live_bytes(blocks),
+                **fused_counts(fwd, upd),
             },
             wall_time_s=time.perf_counter() - t0,
             num_events=(len(fwd.trace.events)
@@ -1047,7 +1068,7 @@ class XMemEstimator:
             persistent_bytes=sum(b.sharded_size for b in blocks
                                  if b.free_t is None),
             oom=sim.oom, sim=sim,
-            breakdown={"num_blocks": len(blocks)},
+            breakdown={"num_blocks": len(blocks), **fused_counts(entry)},
             wall_time_s=time.perf_counter() - t0,
             num_events=len(entry.trace.events))
 

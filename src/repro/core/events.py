@@ -64,6 +64,7 @@ class BlockKind(enum.Enum):
     TEMP = "temp"             # operator-internal scratch
     COLLECTIVE = "collective"  # injected communication buffers (distributed)
     CACHE = "cache"           # KV / recurrent state (serving)
+    FUSED = "fused"           # kept inside an XLA fusion, never in HBM
 
 
 class Phase(enum.Enum):

@@ -22,9 +22,10 @@ XLA-specific passes the original (eager PyTorch) pipeline does not need:
 
 6. donation          -> outputs aliased onto donated inputs (new params /
                         opt state reuse the old buffers; no double count).
-7. fusion folding    -> short-lived outputs of fusible elementwise ops
-                        never materialize in HBM (XLA fuses them); they
-                        are dropped below a size threshold.
+7. fusion folding    -> values the tracer marked as fusion interiors
+                        (``BlockKind.FUSED``: every consumer fuses them,
+                        see ``tracer.py``) never materialize in HBM and
+                        are dropped.
 8. collective inject -> distributed estimation (paper §6.2/6.4's
                         "inject simulated allreduce buffers"): adds
                         COLLECTIVE blocks for gradient reduction buckets
@@ -38,19 +39,6 @@ import dataclasses
 from typing import Callable, Sequence
 
 from .events import BlockKind, BlockLifecycle, MemorySpace, Phase
-
-# Elementwise/layout primitives XLA reliably fuses into consumers —
-# their outputs typically never hit HBM as standalone buffers.
-FUSIBLE_OPS = frozenset({
-    "add", "sub", "mul", "div", "neg", "exp", "log", "tanh", "logistic",
-    "max", "min", "pow", "integer_pow", "sqrt", "rsqrt", "abs", "sign",
-    "convert_element_type", "select_n", "broadcast_in_dim", "reshape",
-    "transpose", "squeeze", "expand_dims", "stop_gradient", "and", "or",
-    "not", "xor", "eq", "ne", "ge", "gt", "le", "lt", "clamp", "erf",
-    "floor", "ceil", "round", "is_finite", "copy", "real", "imag",
-    "slice", "rev", "iota", "cos", "sin", "cumsum", "cumlogsumexp",
-})
-
 
 @dataclasses.dataclass(frozen=True)
 class OffloadPlan:
@@ -113,9 +101,10 @@ class OrchestratorPolicy:
     # drift forever, defeating steady-state replay. The final iteration's
     # outputs stay live (they are the job's results).
     release_outputs_next_iter: bool = True
+    # XLA fuses elementwise chains into their consumers: the tracer marks
+    # the values every consumer fuses (BlockKind.FUSED) and the fold
+    # drops them. False = every op materializes (PyTorch eager).
     fusion_folding: bool = True
-    fusion_max_lifetime: int = 8          # events a fusible temp may span
-    fusion_min_bytes: int = 0             # fold regardless of size by default
     keep_unattributed: bool = True
     # Mixed-precision optimizers upcast each gradient to f32; observed
     # XLA schedules materialize these working copies together across
@@ -269,20 +258,11 @@ class MemoryOrchestrator:
         return out
 
     def fold_fused(self, blocks: list[BlockLifecycle]) -> list[BlockLifecycle]:
-        """Drop blocks XLA fusion would never materialize."""
-        if not self.policy.fusion_folding:
-            return blocks
-        p = self.policy
-        out = []
-        for b in blocks:
-            if (b.op in FUSIBLE_OPS
-                    and b.free_t is not None
-                    and (b.free_t - b.alloc_t) <= p.fusion_max_lifetime
-                    and b.size >= p.fusion_min_bytes
-                    and b.block_kind in (BlockKind.ACTIVATION, BlockKind.TEMP)):
-                continue
-            out.append(b)
-        return out
+        """Drop the fusion interiors the tracer marked: XLA never
+        materializes them (their operands already live on to the fused
+        consumer). Only a trace made with ``fusion_folding`` has any."""
+        _FUSED = BlockKind.FUSED
+        return [b for b in blocks if b.block_kind is not _FUSED]
 
     def inject_optimizer_upcasts(self, blocks: list[BlockLifecycle],
                                  update_start: dict[int, int],
@@ -539,9 +519,9 @@ class MemoryOrchestrator:
                     shard_factor_fn=None) -> list[BlockLifecycle]:
         """The pass pipeline as individual passes — the readable form
         ``run`` is a fusion of (and the oracle it is tested against)."""
-        # fold first: fused temps are never touched by the lifecycle
+        # fold first: FUSED blocks are never touched by the lifecycle
         # passes below (they act on PARAM/OPT/GRAD/INPUT/OUTPUT or on
-        # persistent blocks, which fusible short-lived temps are not), so
+        # persistent blocks, which fusion interiors are not), so
         # dropping them up front shrinks every subsequent pass
         blocks = self.fold_fused(blocks)
         blocks = self.mark_persistent(blocks)
@@ -595,8 +575,7 @@ class MemoryOrchestrator:
                                BlockKind.GRAD)
         _IN, _OUT, _ACT, _TMP = (BlockKind.INPUT, BlockKind.OUTPUT,
                                  BlockKind.ACTIVATION, BlockKind.TEMP)
-        fold = p.fusion_folding
-        fuse_life, fuse_min = p.fusion_max_lifetime, p.fusion_min_bytes
+        _FUSED = BlockKind.FUSED
         out: list[BlockLifecycle] = []
         append = out.append
         upcast_blocks: list[BlockLifecycle] = []
@@ -605,9 +584,7 @@ class MemoryOrchestrator:
             kind = b.block_kind
             free_t = b.free_t
             # fold_fused
-            if (fold and free_t is not None and b.op in FUSIBLE_OPS
-                    and (free_t - b.alloc_t) <= fuse_life
-                    and b.size >= fuse_min and (kind is _ACT or kind is _TMP)):
+            if kind is _FUSED:
                 continue
             # mark_persistent
             if kind is _PARAM or kind is _OPT:

@@ -624,7 +624,9 @@ class SweepService:
         treedefs = (jax.tree_util.tree_structure(p.params),
                     jax.tree_util.tree_structure(p.batch))
         return trace_key(p.fwd_bwd_fn, "fwd", flat, treedefs, kinds,
-                         est.scan_unroll_cap, Phase.FORWARD_BACKWARD), flat
+                         est.scan_unroll_cap, Phase.FORWARD_BACKWARD,
+                         fusion_folding=est.orchestrator.policy.fusion_folding
+                         ), flat
 
     def _estimate_full(self, p: SweepPoint) -> EstimateReport:
         return self.estimator.estimate_training(

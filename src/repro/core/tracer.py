@@ -23,6 +23,19 @@ Control flow is handled like an executor would:
   traced (conservative for peak estimation).
 * ``pjit``/``remat``/``custom_*`` — inlined.
 
+Fusion (``fusion_folding=True``, the XLA presets): a value XLA computes
+inside the fused kernel of its consumers never occupies HBM. Within each
+region, a value is a *fusion interior* when its producer is in
+``FUSIBLE_OPS``, it is not an output of the region (no scan/while carry,
+stacked ``ys`` or jaxpr outvar), and every consumer in the region is
+fusible or a reduction — or, for the layout-only producers, every
+consumer is a ``dot_general``, which absorbs the layout in its dimension
+numbers. Any other consumer (a call, loop, gather/scatter, custom or
+Pallas kernel, ...) makes the value materialize. An interior value is
+recorded as a ``BlockKind.FUSED`` block, which the orchestrator drops,
+and it holds its operand blocks until its own last consumer has run: the
+fused consumer reads them, recursively through chains.
+
 No computation is performed: tracing a trillion-parameter step costs
 milliseconds and zero accelerator involvement — the paper's "zero
 target-GPU overhead" requirement, kept intact.
@@ -48,6 +61,52 @@ from .events import (KIND_CODE, PHASE_CODE, BlockKind, ColumnarTrace,
 
 # Primitive param keys that hold sub-jaxprs to inline.
 _CALL_JAXPR_KEYS = ("jaxpr", "call_jaxpr", "fun_jaxpr")
+
+# Elementwise/layout primitives XLA fuses into their consumers (``add_any``
+# is autodiff's elementwise add of cotangents).
+FUSIBLE_OPS = frozenset({
+    "add", "sub", "mul", "div", "neg", "exp", "log", "tanh", "logistic",
+    "max", "min", "pow", "integer_pow", "sqrt", "rsqrt", "abs", "sign",
+    "convert_element_type", "select_n", "broadcast_in_dim", "reshape",
+    "transpose", "squeeze", "expand_dims", "stop_gradient", "and", "or",
+    "not", "xor", "eq", "ne", "ge", "gt", "le", "lt", "clamp", "erf",
+    "floor", "ceil", "round", "is_finite", "copy", "real", "imag",
+    "slice", "rev", "iota", "cos", "sin", "cumsum", "cumlogsumexp",
+    "add_any", "square", "log1p", "rem", "le_to",
+})
+# Reductions read a fused elementwise producer inside their own kernel.
+_REDUCTIONS = frozenset({
+    "reduce_sum", "reduce_max", "reduce_min", "reduce_prod", "reduce_and",
+    "reduce_or", "argmax", "argmin",
+})
+# Layout-only producers a dot_general absorbs in its dimension numbers.
+_LAYOUT_OPS = frozenset({"transpose", "reshape", "squeeze", "expand_dims"})
+
+
+def fusion_interiors(jaxpr: jcore.Jaxpr) -> set:
+    """The values of one region that XLA keeps inside a fusion (see the
+    module docstring for the rule)."""
+    consumers: dict[Any, list[str]] = defaultdict(list)
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        for v in eqn.invars:
+            if isinstance(v, jcore.Var):
+                consumers[v].append(name)
+    region_outs = {v for v in jaxpr.outvars if isinstance(v, jcore.Var)}
+    interior = set()
+    for eqn in jaxpr.eqns:
+        op = eqn.primitive.name
+        if op not in FUSIBLE_OPS:
+            continue
+        for v in eqn.outvars:
+            uses = consumers.get(v)
+            if not uses or v in region_outs:
+                continue
+            if (all(u in FUSIBLE_OPS or u in _REDUCTIONS for u in uses)
+                    or (op in _LAYOUT_OPS
+                        and all(u == "dot_general" for u in uses))):
+                interior.add(v)
+    return interior
 
 
 def aval_bytes(aval) -> int:
@@ -80,14 +139,19 @@ class _Block:
     op: str = ""
     scope: str = ""
     shape: tuple | None = None    # producing aval dims (sharding input)
+    holds: tuple = ()             # operands a fusion interior keeps alive
 
 
 class JaxprMemoryTracer:
     """Interprets a jaxpr into an ordered stream of MemoryEvents."""
 
     def __init__(self, scan_unroll_cap: int = 3, phase: Phase = Phase.FORWARD_BACKWARD,
-                 iteration: int = 0):
+                 iteration: int = 0, fusion_folding: bool = False):
         self.cap = scan_unroll_cap
+        self.fusion_folding = fusion_folding
+        # per region jaxpr: (jaxpr, use counts, fusion interiors); the
+        # jaxpr is kept so its id stays unique while the entry lives
+        self._regions: dict[int, tuple] = {}
         self.phase = phase
         self.iteration = iteration
         # Events are emitted straight into primitive columns (the
@@ -150,6 +214,8 @@ class JaxprMemoryTracer:
             self._ev_bkind.append(KIND_CODE[b.kind])
             self._ev_shape.append(self._shapes.intern(b.shape))
             self.t += 1
+            for h in b.holds:
+                self._release(h, 1, op, scope)
 
     # ---- use counting ------------------------------------------------------
     @staticmethod
@@ -164,6 +230,17 @@ class JaxprMemoryTracer:
                 counts[v] += 1
         return counts
 
+    def _region(self, jaxpr: jcore.Jaxpr) -> tuple[dict, set]:
+        """Use counts and fusion interiors of a region, once per jaxpr
+        (scan and while bodies are interpreted once per iteration)."""
+        hit = self._regions.get(id(jaxpr))
+        if hit is None:
+            interior = (fusion_interiors(jaxpr) if self.fusion_folding
+                        else set())
+            hit = self._regions[id(jaxpr)] = (
+                jaxpr, self._use_counts(jaxpr), interior)
+        return hit[1], hit[2]
+
     # ---- region interpretation ---------------------------------------------
     def _interpret_region(self, jaxpr: jcore.Jaxpr, bindings: Sequence[_Block],
                           consts: Sequence[_Block] = ()) -> list[_Block]:
@@ -173,7 +250,7 @@ class JaxprMemoryTracer:
         (pre-paid); returned outvar blocks carry one ref per outvar
         occurrence which the caller must dispose of.
         """
-        counts = self._use_counts(jaxpr)
+        counts, interior = self._region(jaxpr)
         env: dict[Any, _Block] = {}
         for v, b in zip(jaxpr.constvars, consts):
             env[v] = b
@@ -219,6 +296,17 @@ class JaxprMemoryTracer:
                     n_uses = counts.get(ov, 0)
                     if isinstance(ov, _DropVar) or n_uses == 0:
                         out_blocks.append(None)
+                        continue
+                    if ov in interior:
+                        holds = tuple(b for b in map(read, eqn.invars)
+                                      if b is not None)
+                        for h in holds:
+                            self._retain(h, 1)
+                        b = self._new_block(
+                            aval_bytes(ov.aval), n_uses, op, scope,
+                            BlockKind.FUSED, shape=aval_shape(ov.aval))
+                        b.holds = holds
+                        out_blocks.append(b)
                         continue
                     out_blocks.append(self._new_block(
                         aval_bytes(ov.aval), n_uses, op, scope,
@@ -450,7 +538,8 @@ class JaxprMemoryTracer:
 
 def trace_fn(fn: Callable, *args, arg_kinds=None, arg_scopes=None,
              scan_unroll_cap: int = 3, phase: Phase = Phase.FORWARD_BACKWARD,
-             iteration: int = 0, **kwargs) -> tuple[Trace, JaxprMemoryTracer]:
+             iteration: int = 0, fusion_folding: bool = False,
+             **kwargs) -> tuple[Trace, JaxprMemoryTracer]:
     """Trace ``fn(*args)`` into a memory-event stream.
 
     ``arg_kinds``/``arg_scopes`` are flat lists aligned with the flattened
@@ -459,14 +548,15 @@ def trace_fn(fn: Callable, *args, arg_kinds=None, arg_scopes=None,
     trace, tr, _, _ = trace_fn_with_shape(
         fn, *args, arg_kinds=arg_kinds, arg_scopes=arg_scopes,
         scan_unroll_cap=scan_unroll_cap, phase=phase, iteration=iteration,
-        **kwargs)
+        fusion_folding=fusion_folding, **kwargs)
     return trace, tr
 
 
 def trace_fn_with_shape(fn: Callable, *args, arg_kinds=None, arg_scopes=None,
                         scan_unroll_cap: int = 3,
                         phase: Phase = Phase.FORWARD_BACKWARD,
-                        iteration: int = 0, **kwargs
+                        iteration: int = 0, fusion_folding: bool,
+                        **kwargs
                         ) -> tuple[Trace, JaxprMemoryTracer, Any, Any]:
     """``trace_fn`` plus the abstract output pytree and the closed jaxpr.
 
@@ -476,6 +566,7 @@ def trace_fn_with_shape(fn: Callable, *args, arg_kinds=None, arg_scopes=None,
     """
     closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(*args, **kwargs)
     tr = JaxprMemoryTracer(scan_unroll_cap=scan_unroll_cap, phase=phase,
-                           iteration=iteration)
+                           iteration=iteration,
+                           fusion_folding=fusion_folding)
     trace = tr.trace_closed_jaxpr(closed, arg_kinds, arg_scopes)
     return trace, tr, out_shape, closed

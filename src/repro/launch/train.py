@@ -70,8 +70,12 @@ def admission_check(cfg, policy: TrainPolicy, shape: ShapeSpec,
         cache_note = (f", trace cache {tc.get('hits', 0)}h/"
                       f"{tc.get('misses', 0)}m"
                       f" [{decision.provenance['source']}]")
+        bd = rep.breakdown
+        fused = (f", fused {bd['fused_blocks']} values "
+                 f"{bd['fused_bytes']/2**30:.2f} GiB"
+                 if "fused_blocks" in bd else "")
         print(f"[xmem] estimated peak {rep.peak_bytes/2**30:.2f} GiB "
-              f"(persistent {rep.persistent_bytes/2**30:.2f}) vs HBM "
+              f"(persistent {rep.persistent_bytes/2**30:.2f}{fused}) vs HBM "
               f"{hbm_bytes/2**30:.0f} GiB -> "
               f"{'ADMIT' if ok else 'REJECT'} "
               f"({decision.wall_s:.2f}s estimation{cache_note})")

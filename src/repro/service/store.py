@@ -9,7 +9,8 @@ processes).
 
 On-disk format: one JSON file per entry, named by the stable sha256 of
 the full trace key (function content digest + avals + treedefs + kinds +
-scan cap + phase + tag — see ``cache.stable_key_digest``). The payload
+scan cap + phase + tag + fusion folding — see
+``cache.stable_key_digest``). The payload
 is the schema-v3 **columnar** trace format (``ColumnarTrace`` /
 ``ColumnarBlocks`` ``to_json``, shape tables included), plus the
 input/output block summaries, the abstract output pytree and the

@@ -106,12 +106,12 @@ class TestTraceCache:
         td = (jax.tree_util.tree_structure(params),)
         kinds = [BlockKind.PARAM] * len(flat)
         k1 = trace_key(_fwd_bwd, "t", flat, td, kinds, 3,
-                       Phase.FORWARD_BACKWARD)
+                       Phase.FORWARD_BACKWARD, fusion_folding=True)
         k2 = trace_key(_fwd_bwd, "t", flat, td, kinds, 5,
-                       Phase.FORWARD_BACKWARD)
+                       Phase.FORWARD_BACKWARD, fusion_folding=True)
         other = [jax.ShapeDtypeStruct((D, H + 1), jnp.float32)] * len(flat)
         k3 = trace_key(_fwd_bwd, "t", other, td, kinds, 3,
-                       Phase.FORWARD_BACKWARD)
+                       Phase.FORWARD_BACKWARD, fusion_folding=True)
         assert len({k1, k2, k3}) == 3
 
     def test_recreated_identical_fn_is_warm(self, shapes):
@@ -163,7 +163,8 @@ class TestTraceCache:
         cache = TraceCache(maxsize=2)
         fns = [lambda i=i: i for i in range(3)]
         for i, f in enumerate(fns):
-            key = trace_key(f, "t", [], (), [], 3, Phase.FORWARD_BACKWARD)
+            key = trace_key(f, "t", [], (), [], 3, Phase.FORWARD_BACKWARD,
+                            fusion_folding=True)
             cache.put(f, key, object())
         assert len(cache) == 2
 

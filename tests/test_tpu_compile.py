@@ -22,6 +22,7 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.launch.device import V5E_HBM_BYTES
 from repro.models import model as M
 from repro.train import TrainPolicy, make_train_step
+from test_fusion_fold import MULTI_CONSUMER_EXP
 
 
 @pytest.fixture(scope="module")
@@ -76,3 +77,17 @@ def test_chip_train_step_fits_one_v5e(one_chip):
     on_device = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                  - ma.alias_size_in_bytes)
     assert on_device < V5E_HBM_BYTES, f"{on_device / 2**30:.2f} GiB"
+
+
+@MULTI_CONSUMER_EXP
+def test_exp_with_two_consumers_stays_out_of_hbm(one_chip, fn, last):
+    """The tracer folds an exp that two fusible consumers read; the chip's
+    compiler must agree. ``x = a @ b`` is f32[4096, 32768] (512 MiB); the
+    exp of it is as large. The compiled temporaries hold x at most: an
+    exp written to HBM beside it would double them."""
+    a, b, w = (4096, 512), (512, 32768), (32768, 128)
+    compiled = jax.jit(fn).lower(*(
+        jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+        for s in (a, b, w))).compile()
+    x_bytes = 4096 * 32768 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * x_bytes
