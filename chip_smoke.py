@@ -145,52 +145,31 @@ def one_chip(cfg, shape, steps: int) -> int:
 
 
 def four_chips(cfg, shape, steps: int, n: int = 4) -> int:
-    """The same job sharded over ``n`` chips (data 1 x model n), placed
-    as ``launch/dryrun.py`` places it; per-device peaks beside the gate's
-    per-device estimate, and the step-0 loss against an unsharded forward
-    on one device. Returns the number of devices used."""
+    """The same job sharded over ``n`` chips (data 1 x model n), laid
+    out and gated by ``launch/train.py:mesh_job`` as ``train_loop`` lays
+    it out; per-device peaks beside the gate's per-device estimate, and
+    the step-0 loss against an unsharded forward on one device. Returns
+    the number of devices used."""
     from functools import partial
 
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.configs.registry import input_specs
-    from repro.distributed.act_sharding import (DEFAULT_RULES,
-                                                logical_axis_rules)
-    from repro.distributed.sharding import (ShardingPolicy, batch_shardings,
-                                            mesh_collective_specs,
-                                            opt_state_shardings,
-                                            param_shardings, shard_factor_fn)
     from repro.launch import device as D
-    from repro.launch.mesh import make_smoke_mesh, mesh_axis_sizes
-    from repro.launch.train import admission_check
+    from repro.launch.mesh import make_mesh, mesh_axis_sizes
+    from repro.launch.train import mesh_job
     from repro.models import model as M
-    from repro.train import SyntheticDataset, TrainPolicy, make_train_step
+    from repro.train import SyntheticDataset, TrainPolicy
 
-    mesh = make_smoke_mesh(n)
+    mesh = make_mesh({"data": 1, "model": n})
     devs = list(mesh.devices.flat)
-    sizes = mesh_axis_sizes(mesh)
-    # no FSDP below 8 B parameters, as dryrun.arch_sharding_policy decides
-    spol = ShardingPolicy(batch_axes=("data",))
-    policy = TrainPolicy()
-    step, opt = make_train_step(cfg, policy)
-    aparams = M.abstract_params(cfg)
-    aopt = jax.eval_shape(opt.init, aparams)
-    bspecs = input_specs(cfg, shape)
-    pshard = param_shardings(aparams, cfg, mesh, spol)
-    oshard = opt_state_shardings(aopt, mesh, spol)
-    bshard = batch_shardings(bspecs, mesh, spol)
     capacity = min(D.hbm_bytes(d) for d in devs)
-    print(f"mesh {sizes}; capacity per device {capacity} B")
+    print(f"mesh {mesh_axis_sizes(mesh)}; capacity per device {capacity} B")
     before_gate = [D.bytes_in_use(d) for d in devs]
-
-    ok, rep = admission_check(
-        cfg, policy, shape, capacity,
-        shard_factor_fn=shard_factor_fn(cfg, sizes, spol, params=aparams,
-                                        opt_state=aopt, batch=bspecs),
-        collective_specs=mesh_collective_specs(sizes, spol))
-    if not ok:
-        raise SmokeFailure("gate REJECT (per-device estimate)")
+    try:
+        job = mesh_job(cfg, shape, TrainPolicy(), mesh, hbm_bytes=capacity)
+    except MemoryError as e:
+        raise SmokeFailure("gate REJECT (per-device estimate)") from e
+    rep = job.report
     print(f"device bytes_in_use before the gate {before_gate}, after it "
           f"{[D.bytes_in_use(d) for d in devs]} (expected unchanged)")
 
@@ -199,37 +178,25 @@ def four_chips(cfg, shape, steps: int, n: int = 4) -> int:
     compiles = D.CompileCounter().install()
     after_first = None
     try:
-        with mesh, logical_axis_rules(mesh, DEFAULT_RULES):
-            params = jax.jit(partial(M.init_params, cfg),
-                             out_shardings=pshard)(jax.random.key(0))
-            opt_state = jax.jit(opt.init, out_shardings=oshard)(params)
-            host_params = jax.device_get(params)     # for the reference
-            step_fn = jax.jit(step, donate_argnums=(0, 1), out_shardings=(
-                NamedSharding(mesh, P()), pshard, oshard))
-            for i in range(steps):
-                t0 = time.perf_counter()
-                batch = jax.device_put(ds.batch(i), bshard)
-                loss, params, opt_state = step_fn(params, opt_state, batch)
-                jax.block_until_ready((loss, params, opt_state))
-                step_s.append(time.perf_counter() - t0)
-                losses.append(float(loss))
-                if i == 0:
-                    after_first = compiles.count
+        params, opt_state = job.place(partial(M.init_params, cfg),
+                                      jax.random.key(0))
+        host_params = jax.device_get(params)     # for the reference
+        for i in range(steps):
+            t0 = time.perf_counter()
+            loss, params, opt_state = job.step(params, opt_state,
+                                               job.put(ds.batch(i)))
+            jax.block_until_ready((loss, params, opt_state))
+            step_s.append(time.perf_counter() - t0)
+            losses.append(float(loss))
+            if i == 0:
+                after_first = compiles.count
     except Exception as e:
         _oom_note(e, capacity)
         raise
     _check_losses(losses)
     peaks = [D.peak_bytes_in_use(d) for d in devs]
     del params, opt_state
-
-    def on(tree, shardings):
-        return jax.tree_util.tree_map(
-            lambda l, s: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=s),
-            tree, shardings)
-    with mesh, logical_axis_rules(mesh, DEFAULT_RULES):
-        temps = step_fn.lower(on(aparams, pshard), on(aopt, oshard),
-                              on(bspecs, bshard)).compile(
-        ).memory_analysis().temp_size_in_bytes
+    temps = job.lower().compile().memory_analysis().temp_size_in_bytes
     print(f"gate per-device estimate {rep.peak_bytes} B "
           f"({rep.peak_bytes / GiB:.3f} GiB), persistent "
           f"{rep.persistent_bytes} B; compiled temporaries per device "

@@ -49,8 +49,9 @@ from .analyzer import classify_blocks, phase_peaks
 from .cache import (BlockInfo, GLOBAL_TRACE_CACHE, TraceCache, TracedPhase,
                     trace_key)
 from .events import (KIND_CODE, BlockKind, BlockLifecycle, PeriodicBlocks,
-                     Phase, peak_live_bytes, periodic_breakdown_peaks,
-                     periodic_breakdown_peaks_fast, reduced_for_breakdown)
+                     Phase, liveness_curve, peak_live_bytes,
+                     periodic_breakdown_peaks, periodic_breakdown_peaks_fast,
+                     reduced_for_breakdown)
 from .orchestrator import (CollectiveSpec, MemoryOrchestrator, OffloadPlan,
                            OrchestratorPolicy)
 from .simulator import MemorySimulator, SimResult, split_blocks_by_space
@@ -232,6 +233,29 @@ def fused_counts(*phases: TracedPhase | None) -> dict:
         n += int(m.sum())
         nbytes += int(cols.size[m].sum())
     return {"fused_blocks": n, "fused_bytes": nbytes}
+
+
+def collective_counts(blocks) -> dict:
+    """``collective_blocks``: the collective staging buffers
+    (``MemoryOrchestrator.inject_collectives``) live at the
+    composition's peak of live bytes; ``collective_bytes``, their
+    per-device bytes. ``blocks`` is a flat lifecycle list or a
+    ``PeriodicBlocks`` composition."""
+    out = {"collective_blocks": 0, "collective_bytes": 0}
+    if isinstance(blocks, PeriodicBlocks):
+        if not any(b.block_kind is BlockKind.COLLECTIVE
+                   for b in blocks.iter_groups()):
+            return out
+        blocks = blocks.materialize()
+    staged = [b for b in blocks if b.block_kind is BlockKind.COLLECTIVE]
+    if not staged:
+        return out
+    # the first time live bytes reach their maximum
+    t = max(liveness_curve(blocks), key=lambda tv: tv[1])[0]
+    live = [b for b in staged
+            if b.alloc_t <= t and (b.free_t is None or b.free_t > t)]
+    return {"collective_blocks": len(live),
+            "collective_bytes": sum(b.sharded_size for b in live)}
 
 
 def flatten_kinds(args_with_kinds: Sequence[tuple]) -> tuple[list, list[BlockKind], list[str]]:
@@ -845,13 +869,14 @@ class XMemEstimator:
                 MemorySpace.DEVICE_HBM,
                 PeriodicBlocks([], [], pb.n_cycles, pb.period, [],
                                dict(pb.meta)))
-        liveness_peak, phase_pk = periodic_breakdown_peaks_fast(
-            reduced_for_breakdown(bd_pb))
+        bd_pb = reduced_for_breakdown(bd_pb)
+        liveness_peak, phase_pk = periodic_breakdown_peaks_fast(bd_pb)
         breakdown = {
             "phase_peaks": phase_pk,
             "num_blocks": pb.num_blocks,
             "liveness_peak": liveness_peak,
             **fused_counts(fwd, upd),
+            **collective_counts(bd_pb),
         }
         if offload_stats is not None:
             breakdown["space_peaks"] = sim.stats.get("space_peaks", {})
@@ -1002,6 +1027,7 @@ class XMemEstimator:
                 "num_blocks": len(blocks),
                 "liveness_peak": peak_live_bytes(blocks),
                 **fused_counts(fwd, upd),
+                **collective_counts(blocks),
             },
             wall_time_s=time.perf_counter() - t0,
             num_events=(len(fwd.trace.events)

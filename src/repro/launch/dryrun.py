@@ -38,16 +38,17 @@ from ..configs.registry import (abstract_cache,           # noqa: E402
                                 decode_input_specs, input_specs)
 from ..distributed.act_sharding import (DEFAULT_RULES,    # noqa: E402
                                         logical_axis_rules)
-from ..distributed.sharding import (ShardingPolicy,       # noqa: E402
-                                    batch_shardings, cache_shardings,
-                                    opt_state_shardings, param_shardings)
+from ..distributed.sharding import (batch_shardings,      # noqa: E402
+                                    cache_shardings, opt_state_shardings,
+                                    param_shardings)
 from ..models import model as M                           # noqa: E402
 from ..train.train_step import (TrainPolicy,              # noqa: E402
                                 make_serve_step, make_train_step)
 from .analytic import analytic_bytes, analytic_flops     # noqa: E402
 from .hlo_analysis import (collective_bytes as hlo_collective_bytes,  # noqa: E402
                            cost_analysis_of)
-from .mesh import make_production_mesh, mesh_axis_sizes   # noqa: E402
+from .mesh import (arch_sharding_policy,                 # noqa: E402
+                   make_production_mesh, mesh_axis_sizes)
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
@@ -63,14 +64,6 @@ def arch_train_policy(arch: str, cfg) -> TrainPolicy:
     if n > 8e9:
         return TrainPolicy(optimizer="adamw", microbatches=4, clip_norm=1.0)
     return TrainPolicy(optimizer="adamw", microbatches=1, clip_norm=1.0)
-
-
-def arch_sharding_policy(cfg, mesh) -> ShardingPolicy:
-    axes = ("data", "pod") if "pod" in mesh.axis_names else ("data",)
-    fsdp = cfg.param_count() > 8e9     # ZeRO-3 for everything sizable
-    return ShardingPolicy(fsdp=fsdp, fsdp_axes=axes,
-                          batch_axes=tuple(a for a in ("pod", "data")
-                                           if a in mesh.axis_names))
 
 
 def _with_moe_groups(cfg, mesh):
